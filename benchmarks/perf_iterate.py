@@ -12,6 +12,7 @@ Usage:
   PYTHONPATH=src python -m benchmarks.perf_iterate --cell llama3_train
 """
 import os
+# lowers on 512 virtual CPU devices, never on an attached accelerator
 os.environ.setdefault("XLA_FLAGS",
                       "--xla_force_host_platform_device_count=512")
 
@@ -23,6 +24,8 @@ import jax
 from repro import configs
 from repro.dist import hlo_cost
 from repro.layers.common import SHAPES
+
+jax.config.update("jax_platforms", "cpu")
 
 OUT = os.path.join(os.path.dirname(__file__), "..", "experiments", "perf")
 
@@ -147,7 +150,8 @@ def _cell_llama3(results):
   t = attention_tile_bytes(rep)
   results.append(report("A3/A4 wedge+1024 (+flash adj)", rep, roof, mem,
                         extra=f"adj_memory={roof.memory_s - t/819e9:.3f}s"))
-  m128 = make_mesh((128, 2), ("data", "model"), devices=jax.devices()[:256])
+  m128 = make_mesh((128, 2), ("data", "model"),
+                   devices=jax.devices("cpu")[:256])
   rep, roof, mem = lower_cell("llama3-8b", "train_4k", m128, cfg_patch=wedge)
   t = attention_tile_bytes(rep)
   results.append(report("A7 +mesh(128,2)", rep, roof, mem,
@@ -177,7 +181,8 @@ def _cell_ds2(results):
   mesh = dryrun.production_meshes(multi_pod=False)["single"]
   rep, roof, mem = lower_cell("deepspeech2-wsj", "train_4k", mesh)
   results.append(report("C0 baseline TP=16", rep, roof, mem))
-  dp = make_mesh((256, 1), ("data", "model"), devices=jax.devices()[:256])
+  dp = make_mesh((256, 1), ("data", "model"),
+                 devices=jax.devices("cpu")[:256])
   rep, roof, mem = lower_cell("deepspeech2-wsj", "train_4k", dp)
   results.append(report("C2 pure-DP (256,1)", rep, roof, mem))
 

@@ -38,7 +38,7 @@ import re
 from typing import Optional
 
 import jax.numpy as jnp
-from jax import core
+from jax.extend import core
 
 #: unary structural ops provenance flows through (first operand only)
 TRANSPARENT = frozenset({

@@ -33,9 +33,6 @@ Invariants, per `LMEngine.compile_stats`:
   prefill == len(prefill_buckets)            bucketed, nothing beyond
   replay, window, insert each <= 1           auxiliary programs stable
 
-A -1 from compile_stats means the runtime does not expose jit cache
-sizes; the check is skipped (reported in target info), never failed.
-
 Families: the three token-driven LMs (qwen3, zamba2, xlstm) run the
 LMEngine checks; deepspeech runs the speech-fleet check. Whisper
 decodes against encoder memory the engine does not synthesize and is
@@ -103,9 +100,6 @@ def check_retrace_stability(
             check="retrace_stability", config=name, policy=policy,
             program="lifecycle", key=key, detail=detail))
 
-      if stats["step"] < 0:
-        info["skipped"] = "jit cache sizes unavailable on this runtime"
-        continue
       if stats["step"] != 1:
         fail(f"step-cache:{stats['step']}",
              f"decode step compiled {stats['step']} signatures across a "
@@ -211,9 +205,6 @@ def check_prefix_splice_stability(
              f"the shared-prefix scenario produced no cache hits "
              f"({cs}) — the splice path never ran, so its stability "
              f"was not exercised")
-      if warm["step"] < 0:
-        info["skipped"] = "jit cache sizes unavailable on this runtime"
-        continue
       if set(warm["prefill_buckets"]) != set(cold["prefill_buckets"]):
         fail(f"prefill-signatures:{sorted(warm['prefill_buckets'])}",
              f"splice path changed the prefill signature set: cold "
@@ -303,9 +294,6 @@ def check_spec_window_stability(
             check="spec_window_stability", config=name, policy=policy,
             program="lifecycle", key=key, detail=detail))
 
-      if stats["window"] < 0:
-        info["skipped"] = "jit cache sizes unavailable on this runtime"
-        continue
       if stats["window"] != 1:
         fail(f"window-cache:{stats['window']}",
              f"the batched verify window compiled {stats['window']} "
@@ -376,9 +364,6 @@ def check_speech_fleet_stability(
             check="speech_fleet_stability", config=name, policy=policy,
             program="lifecycle", key=key, detail=detail))
 
-      if stats["frame_step"] < 0:
-        info["skipped"] = "jit cache sizes unavailable on this runtime"
-        continue
       if stats["frame_step"] != 1:
         fail(f"frame-step-cache:{stats['frame_step']}",
              f"the masked speech frame step compiled "

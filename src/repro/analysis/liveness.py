@@ -29,7 +29,8 @@ from __future__ import annotations
 
 import dataclasses
 
-from jax import core
+from jax.core import DropVar
+from jax.extend import core
 
 from repro.analysis.jaxpr_walk import _sub_jaxprs
 
@@ -81,7 +82,7 @@ def _transient_peak(jaxpr: core.Jaxpr, credited: frozenset) -> int:
       inner = max(inner, _transient_peak(sub, credited))
     peak = max(peak, live + inner)
     for v in eqn.outvars:
-      if isinstance(v, core.DropVar):
+      if isinstance(v, DropVar):
         continue
       b = 0 if v in credited else _var_bytes(v)
       owned[v] = b
@@ -93,7 +94,7 @@ def _transient_peak(jaxpr: core.Jaxpr, credited: frozenset) -> int:
     # outputs never read again (and not program outputs) die immediately
     for v in eqn.outvars:
       if (v in owned and v not in last_use and v not in never_free
-          and not isinstance(v, core.DropVar)):
+          and not isinstance(v, DropVar)):
         live -= owned.pop(v)
   return peak
 

@@ -1,6 +1,7 @@
 """Architecture registry: 10 assigned archs + the paper's own DS2 config.
 
-  get_config(name)  — full production config (exercised via dry-run only)
+  get_config(name)  — full production config (deepspeech2-wsj runs on one
+                      TPU v5e via chip_smoke.py; the rest compile only)
   get_smoke(name)   — reduced same-family config (CPU-runnable)
   shapes_for(name)  — the assigned ShapeConfigs minus documented skips
 """

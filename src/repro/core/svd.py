@@ -22,6 +22,17 @@ import numpy as np
 from repro.core.factored import FactoredLinear, map_factored_leaves
 
 
+def _host(x: jax.Array) -> jax.Array:
+  """x on the host CPU device. The warmstart SVDs run there: they run once
+  per leaf, and an accelerator compiles a separate SVD program for every
+  leaf shape (minutes per model on a TPU)."""
+  return jax.device_put(x, jax.devices("cpu")[0])
+
+
+def _to_default_device(x: jax.Array) -> jax.Array:
+  return jnp.asarray(np.asarray(x))
+
+
 def balanced_split(w: jax.Array, rank: Optional[int] = None
                    ) -> tuple[jax.Array, jax.Array]:
   """Factor w (m, n) into u (m, r), v (r, n) with u = U sqrt(S), v = sqrt(S)V^T.
@@ -33,11 +44,12 @@ def balanced_split(w: jax.Array, rank: Optional[int] = None
   if w.ndim != 2:
     raise ValueError(f"balanced_split expects 2D, got {w.shape}")
   r = min(w.shape) if rank is None else rank
-  uu, s, vt = jnp.linalg.svd(w.astype(jnp.float32), full_matrices=False)
+  uu, s, vt = jnp.linalg.svd(_host(w).astype(jnp.float32),
+                             full_matrices=False)
   sq = jnp.sqrt(s[:r])
   u = (uu[:, :r] * sq[None, :]).astype(w.dtype)
   v = (sq[:, None] * vt[:r, :]).astype(w.dtype)
-  return u, v
+  return _to_default_device(u), _to_default_device(v)
 
 
 def explained_variance_rank(s: jax.Array | np.ndarray, threshold: float) -> int:
@@ -123,7 +135,8 @@ def truncate_leaf(leaf: FactoredLinear, spec: TruncationSpec,
       u, v, _ = activation_split(w, np.asarray(cov), spec)
       return FactoredLinear(w=None, u=u, v=v, name=leaf.name,
                             group=leaf.group)
-    s = np.asarray(jnp.linalg.svd(w.astype(jnp.float32), compute_uv=False))
+    s = np.asarray(jnp.linalg.svd(_host(w).astype(jnp.float32),
+                                  compute_uv=False))
     r = spec.pick(s)
     u, v = balanced_split(w, r)
     return FactoredLinear(w=None, u=u, v=v, name=leaf.name, group=leaf.group)
@@ -149,7 +162,7 @@ def truncate_leaf(leaf: FactoredLinear, spec: TruncationSpec,
     uvs = [activation_split(m, c, fixed)[:2] for m, c in zip(flat, covs)]
     us, vs = [u for u, _ in uvs], [v for _, v in uvs]
   else:
-    svals = [np.asarray(jnp.linalg.svd(m.astype(jnp.float32),
+    svals = [np.asarray(jnp.linalg.svd(_host(m).astype(jnp.float32),
                                        compute_uv=False))
              for m in flat]
     r = max(spec.pick(s) for s in svals)

@@ -194,6 +194,7 @@ class _Instr:
   operands: str                    # text inside the opcode's parens
   attrs: str                       # text after the closing paren
   line: str
+  name: str = ""                   # instruction name, without the "%"
 
 
 #: sentinel opcode for instruction lines `_split_instr` could not parse
@@ -211,10 +212,14 @@ _CALLED_RE = {
 }
 
 
+_OPERAND_NAME_RE = re.compile(r"%([\w.\-]+)")
+
+
 def _split_instr(line: str) -> Optional[_Instr]:
   eq = line.find(" = ")
   if eq < 0:
     return None
+  name = line[:eq].split()[-1].lstrip("%") if line[:eq].strip() else ""
   rest = line[eq + 3:]
   # result shape: either "(tuple, ...)" or "dtype[dims]{layout}"
   if rest.startswith("("):
@@ -243,7 +248,17 @@ def _split_instr(line: str) -> Optional[_Instr]:
       end = j
       break
   return _Instr(opcode=opcode, shape=shape, operands=rest[par + 1:end],
-                attrs=rest[end + 1:], line=line)
+                attrs=rest[end + 1:], line=line, name=name)
+
+
+def _operand_shapes(ins: _Instr, shapes: dict) -> list[str]:
+  """Result shape of each operand, looked up by name in its computation.
+
+  Compiled HLO text names operands without their shapes
+  (`dot(%a.1, %b.1)`), so operand shapes come from the instructions that
+  define them; an operand defined nowhere in the computation adds none."""
+  return [shapes[n] for n in _OPERAND_NAME_RE.findall(ins.operands)
+          if n in shapes]
 
 
 def _parse_computations(text: str) -> tuple[dict, Optional[str]]:
@@ -275,9 +290,9 @@ def _parse_computations(text: str) -> tuple[dict, Optional[str]]:
   return comps, entry
 
 
-def _dot_flops(ins: _Instr) -> float:
+def _dot_flops(ins: _Instr, operands: list[str]) -> float:
   out = _first_array_dims(ins.shape) or []
-  lhs = _first_array_dims(ins.operands) or []
+  lhs = (_first_array_dims(operands[0]) if operands else None) or []
   m = _CONTRACT_RE.search(ins.attrs)
   contract = 1.0
   if m:
@@ -290,12 +305,11 @@ def _dot_flops(ins: _Instr) -> float:
   return 2.0 * out_elems * contract
 
 
-def _conv_flops(ins: _Instr) -> float:
+def _conv_flops(ins: _Instr, operands: list[str]) -> float:
   out = _first_array_dims(ins.shape) or []
-  shapes = _SHAPE_RE.findall(ins.operands)
-  if len(shapes) < 2:
+  if len(operands) < 2:
     return 0.0
-  kernel = _dims(shapes[1][1])
+  kernel = _first_array_dims(operands[1]) or []
   k_elems = 1.0
   for d in kernel:
     k_elems *= d
@@ -329,7 +343,9 @@ def analyze_module(hlo_text: str, n_devices: int = 1) -> CostReport:
       return memo[name]
     memo[name] = CostReport()      # cycle guard (HLO graphs are acyclic)
     rep = CostReport()
-    for ins in comps.get(name, ()):
+    instrs = comps.get(name, ())
+    shapes = {ins.name: ins.shape for ins in instrs if ins.name}
+    for ins in instrs:
       op = ins.opcode
       for d, _ in _SHAPE_RE.findall(ins.shape):
         if d not in _DTYPE_BITS:
@@ -374,15 +390,17 @@ def analyze_module(hlo_text: str, n_devices: int = 1) -> CostReport:
           rep.flops += inner.flops          # dots fused into the kernel
           rep.dot_flops += inner.dot_flops
         # fall through: the fusion boundary is the HBM traffic
+      operands = _operand_shapes(ins, shapes)
+      operand_bytes = sum(_shape_bytes(o) for o in operands)
       if op == "dot":
-        f = _dot_flops(ins)
+        f = _dot_flops(ins, operands)
         rep.flops += f
         rep.dot_flops += f
       elif op == "convolution":
-        rep.flops += _conv_flops(ins)
+        rep.flops += _conv_flops(ins, operands)
       base = op.replace("-start", "")
       if base in COLLECTIVE_OPS and not op.endswith("-done"):
-        payload = max(_shape_bytes(ins.shape), _shape_bytes(ins.operands))
+        payload = max(_shape_bytes(ins.shape), operand_bytes)
         g = _group_size(ins.line, n_devices)
         wire = payload * _wire_factor(base, g)
         rep.collective_bytes += payload
@@ -392,7 +410,7 @@ def analyze_module(hlo_text: str, n_devices: int = 1) -> CostReport:
             rep.collective_by_kind.get(base, 0.0) + wire)
       if op in _FREE_OPS or op.endswith("-done"):
         continue
-      b = _shape_bytes(ins.shape) + _shape_bytes(ins.operands)
+      b = _shape_bytes(ins.shape) + operand_bytes
       rep.hbm_bytes += b
       out_b = _shape_bytes(ins.shape)
       if out_b:

@@ -27,21 +27,23 @@ def _kernel(x_ref, w_ref, xs_ref, ws_ref, y_ref, acc_ref, *, nm: int):
   def _init():
     acc_ref[...] = jnp.zeros_like(acc_ref)
 
-  acc_ref[...] += jnp.dot(x_ref[...].astype(jnp.int32),
-                          w_ref[...].astype(jnp.int32),
+  # s8 x s8 operands straight into the MXU; Mosaic has no s32 matmul
+  acc_ref[...] += jnp.dot(x_ref[...], w_ref[...],
                           preferred_element_type=jnp.int32)
 
   @pl.when(j == nm - 1)
   def _dequant():
     y_ref[...] = (acc_ref[...].astype(jnp.float32) *
-                  xs_ref[...].astype(jnp.float32)[:, None] *
-                  ws_ref[...].astype(jnp.float32)[None, :])
+                  xs_ref[...].astype(jnp.float32) *
+                  ws_ref[...].astype(jnp.float32))
 
 
 def int8_gemm(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
               w_scale: jax.Array, *, block_m: int = 512, block_n: int = 512,
               interpret: bool = False) -> jax.Array:
-  """x_q: (b, m) s8; w_q: (m, n) s8; x_scale: (b,); w_scale: (n,) -> f32."""
+  """x_q: (b, m) s8; w_q: (m, n) s8; x_scale: (b, 1); w_scale: (1, n)
+  -> f32 (b, n). The scales are 2-D so their blocks keep the (8, 128)
+  tiling Mosaic requires of every operand."""
   b, m = x_q.shape
   n = w_q.shape[1]
   bm = min(block_m, m)
@@ -55,8 +57,8 @@ def int8_gemm(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
       in_specs=[
           pl.BlockSpec((b, bm), lambda i, j: (0, j)),
           pl.BlockSpec((bm, bn), lambda i, j: (j, i)),
-          pl.BlockSpec((b,), lambda i, j: (0,)),
-          pl.BlockSpec((bn,), lambda i, j: (i,)),
+          pl.BlockSpec((b, 1), lambda i, j: (0, 0)),
+          pl.BlockSpec((1, bn), lambda i, j: (0, i)),
       ],
       out_specs=pl.BlockSpec((b, bn), lambda i, j: (0, i)),
       out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),

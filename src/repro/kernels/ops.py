@@ -105,8 +105,8 @@ def int8_gemm(x_q, w_q, x_scale, w_scale, *, block_m: int | None = None,
     return ref.int8_gemm(x_q, w_q, x_scale, w_scale)
   xp = _pad_to(_pad_to(x_q, 0, SUBLANE), 1, LANE)
   wp = _pad_to(_pad_to(w_q, 0, LANE), 1, LANE)
-  xsp = _pad_to(x_scale, 0, SUBLANE)
-  wsp = _pad_to(w_scale, 0, LANE)
+  xsp = _pad_to(x_scale, 0, SUBLANE)[:, None]
+  wsp = _pad_to(w_scale, 0, LANE)[None, :]
   blocks = _fit_blocks(
       "int8_gemm", {"block_m": xp.shape[1], "block_n": wp.shape[1]},
       {"block_m": block_m, "block_n": block_n})

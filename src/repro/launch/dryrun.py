@@ -1,4 +1,6 @@
 import os
+# The dry-run compiles on 512 virtual CPU devices, never on an attached
+# accelerator (which another process may need).
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Multi-pod dry-run: lower + compile every (arch x shape x mesh) cell.
@@ -40,12 +42,14 @@ from repro.models import deepspeech
 from repro.models.api import get_model
 from repro.optim import AdamWConfig, make_optimizer
 
+jax.config.update("jax_platforms", "cpu")
+
 OUT_DIR = os.path.join(os.path.dirname(__file__), "..", "..", "..",
                        "experiments", "dryrun")
 
 
 def production_meshes(multi_pod: Optional[bool] = None) -> dict:
-  devs = jax.devices()
+  devs = jax.devices("cpu")
   assert len(devs) >= 512, "dry-run needs the 512-device XLA_FLAGS header"
   meshes = {}
   if multi_pod is not True:

@@ -1,7 +1,7 @@
 """Serving driver: --arch selects any decodable config; drives a queue of
 mixed-length requests through the continuous-batching LMEngine (or streams
-speech through the DS2 server). Smoke configs run on CPU; full configs
-target pods.
+speech through the DS2 server). Smoke configs run on CPU; --full serves
+the published config (deepspeech2-wsj at full width fits one TPU v5e).
 
 Example:
   PYTHONPATH=src python -m repro.launch.serve --arch qwen3-4b \
@@ -18,6 +18,7 @@ import numpy as np
 from repro import configs
 from repro.data.speech import SpeechDataConfig, batch_at
 from repro.models.api import get_model
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import LMEngine, StreamingSpeechServer
 
 
@@ -79,6 +80,7 @@ def main() -> None:
   ap.add_argument("--prefix-cache-mb", type=float, default=256.0,
                   help="byte-accounted LRU capacity for --prefix-cache")
   args = ap.parse_args()
+  enable_compile_cache()
   if args.adapt_rank and args.draft_rank is None:
     ap.error("--adapt-rank needs --draft-rank (a starting rank to walk)")
   if args.adapt_rank and args.quantize:

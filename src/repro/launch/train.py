@@ -1,8 +1,8 @@
 """Training driver: --arch selects any of the 11 configs.
 
-On this CPU container the reduced (smoke) configs run for real; the full
-configs are exercised through dryrun.py. On a TPU pod the same driver
-takes --full and the production mesh.
+By default it trains the reduced (smoke) config, which runs on the CPU.
+--full trains the published config; deepspeech2-wsj at full width runs on
+one TPU v5e (`chip_smoke.py` drives the same Trainer path there).
 
 Examples:
   PYTHONPATH=src python -m repro.launch.train --arch deepspeech2-wsj \
@@ -24,6 +24,7 @@ from repro.core.svd import TruncationSpec
 from repro.core.tracenorm import RegularizerConfig
 from repro.data import lm as lm_data
 from repro.data import speech as speech_data
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.training import TrainConfig, Trainer
 
 
@@ -46,6 +47,7 @@ def main() -> None:
   ap.add_argument("--checkpoint-dir", default=None)
   ap.add_argument("--seed", type=int, default=0)
   args = ap.parse_args()
+  enable_compile_cache()
 
   cfg = (configs.get_config(args.arch) if args.full
          else configs.get_smoke(args.arch))

@@ -149,13 +149,10 @@ def _next_pow2(n: int) -> int:
 
 
 def _jit_cache_size(fn) -> int:
-  """Compiled-signature count of one jit wrapper (-1 if the runtime does
-  not expose it). Each entry is one traced+compiled input signature, so
-  a shape-stable serving loop holds this at 1 per program."""
-  try:
-    return int(fn._cache_size())
-  except AttributeError:
-    return -1
+  """Compiled-signature count of one jit wrapper. Each entry is one
+  traced+compiled input signature, so a shape-stable serving loop holds
+  this at 1 per program."""
+  return int(fn._cache_size())
 
 
 def make_prefill_program(api, cfg: ModelConfig, cs, policy, axes):
@@ -365,8 +362,7 @@ class LMEngine:
     1, "prefill" at exactly len(prefill_buckets), and the auxiliary
     programs at <= 1 each. A higher count means a signature silently
     re-traced (and recompiled) mid-serve. `repro.analysis`'s
-    retrace-stability and prefix-splice-stability checks assert this;
-    values of -1 mean the runtime does not expose jit cache sizes.
+    retrace-stability and prefix-splice-stability checks assert this.
 
     "prefill_calls" maps "BxL" bucket names to invocation counts since
     init/reset() — benches and the auditor read cache effectiveness
@@ -1017,6 +1013,7 @@ class SpeechResult:
   uid: int
   labels: list                  # collapsed greedy-CTC label sequence
   frames: int                   # raw mel frames consumed
+  log_probs: np.ndarray         # (t', vocab) per-frame CTC log-probs
 
 
 class _SpeechSlot:
@@ -1027,7 +1024,7 @@ class _SpeechSlot:
   labels emitted so far. The speech sibling of `_SlotState`."""
 
   __slots__ = ("uid", "feats", "fed", "labels", "prev", "s1", "s2",
-               "pending", "flushed")
+               "pending", "flushed", "log_probs")
 
   def __init__(self, uid, feats, s1, s2):
     self.uid = uid
@@ -1038,6 +1035,7 @@ class _SpeechSlot:
     self.s1, self.s2 = s1, s2
     self.pending = collections.deque()   # (gru_in,) frames to decode
     self.flushed = False          # frontend drained (right edge padded)
+    self.log_probs: list = []     # (vocab,) per decoded frame
 
   @property
   def done(self) -> bool:
@@ -1208,9 +1206,11 @@ class StreamingSpeechServer:
         mask[i] = True
       log_probs, self.state = self._frame_step(
           self.params, self.state, jnp.asarray(x), jnp.asarray(mask))
-      best = np.asarray(jnp.argmax(log_probs, axis=-1))
+      rows = np.asarray(log_probs)
+      best = rows.argmax(axis=-1)
       for i in live:
         slot, b = self._slots[i], int(best[i])
+        slot.log_probs.append(rows[i])
         if b != 0 and b != slot.prev:
           slot.labels.append(b)
           emitted[i].append(b)
@@ -1270,8 +1270,10 @@ class StreamingSpeechServer:
       self._decode_pending()
       for i, slot in enumerate(self._slots):
         if slot is not None and slot.done:
-          results.append(SpeechResult(uid=slot.uid, labels=slot.labels,
-                                      frames=int(slot.feats.shape[0])))
+          results.append(SpeechResult(
+              uid=slot.uid, labels=slot.labels,
+              frames=int(slot.feats.shape[0]),
+              log_probs=np.stack(slot.log_probs)))
           self._slots[i] = None
     self._mode = None
     return results
@@ -1283,7 +1285,7 @@ class StreamingSpeechServer:
     return self.busy_steps / total if total else 0.0
 
   def compile_stats(self) -> dict:
-    """Jit cache sizes (-1: runtime doesn't expose them). The fleet
+    """Jit cache sizes. The fleet
     contract mirrors LMEngine's: `frame_step` == 1 ever — admits,
     retires, refills, mask patterns and mixed lengths never re-trace —
     and each conv stage holds one signature per pow2 window bucket."""

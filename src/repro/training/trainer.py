@@ -22,7 +22,7 @@ from repro.core.schedule import TwoStageSchedule
 from repro.core.tracenorm import (RegularizerConfig, regularization_loss,
                                   trace_norm_metrics)
 from repro.dist.sharding import (Constraint, identity_constraint,
-                                 make_constraint)
+                                 make_constraint, param_shardings)
 from repro.layers.common import ModelConfig
 from repro.models.api import ModelApi, get_model
 from repro.optim import AdamWConfig, make_optimizer
@@ -110,12 +110,13 @@ class Trainer:
     self.schedule = schedule
     self.plan = plan or FactorizationPlan()
     self.api = get_model(model_cfg)
+    self.mesh = mesh
     self.cs = make_constraint(mesh, model_cfg, batch_size)
     rng = jax.random.PRNGKey(0) if rng is None else rng
     params = self.api.init(rng, model_cfg)
     if schedule is not None and schedule.regularizer.kind == "trace":
       params = to_stage1(params, self.plan)     # full-rank factored form
-    self.params = params
+    self.params = self._place(params)
     self.step = 0
     self.stage = 1 if schedule is not None else 0
     self._lr_scale = 1.0
@@ -124,6 +125,13 @@ class Trainer:
     self.ckpt = (CheckpointManager(train_cfg.checkpoint_dir)
                  if train_cfg.checkpoint_dir else None)
     self.metrics_history: list[dict] = []
+
+  def _place(self, params):
+    """Lay params out over the mesh by the sharding rules (they would
+    otherwise sit whole on the default device until the first step)."""
+    if self.mesh is None:
+      return params
+    return jax.device_put(params, param_shardings(params, self.mesh))
 
   def _current_reg(self) -> RegularizerConfig:
     if self.schedule is None:
@@ -152,8 +160,8 @@ class Trainer:
     if (self.schedule is None or self.stage != 1 or
         self.step < self.schedule.transition_step):
       return False
-    self.params = to_stage2(self.params, self.plan,
-                            self.schedule.truncation)
+    self.params = self._place(to_stage2(self.params, self.plan,
+                                        self.schedule.truncation))
     self.stage = 2
     self._lr_scale = self.schedule.stage2_lr_scale()
     self._build(reg=RegularizerConfig(kind="none"))

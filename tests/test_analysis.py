@@ -117,8 +117,6 @@ def test_retrace_instability_is_observable():
   eng = LMEngine(cfg, params, batch_size=2, max_len=16)
   eng.generate(np.array([[1, 2], [3, 4]]), steps=2)
   stats = eng.compile_stats()
-  if stats["step"] < 0:
-    pytest.skip("runtime does not expose jit cache sizes")
   assert stats["step"] == 1
   # seed the violation: feed the donated step a rogue batch-3 signature
   rogue = eng._init_state(3)
@@ -151,8 +149,6 @@ def test_lifecycle_check_green():
   assert findings == [], findings
   (info,) = infos
   stats = info["compile_stats"]
-  if stats["step"] < 0:
-    pytest.skip("runtime does not expose jit cache sizes")
   assert stats["step"] == 1
   # the serve cycle really hit two prompt buckets + the refill path
   assert len(stats["prefill_buckets"]) >= 2
@@ -167,8 +163,6 @@ def test_prefix_splice_check_green():
   # the scenario really exercised the splice path, not a vacuous pass
   assert info["cache_stats"]["hits"] >= 1
   stats = info["compile_stats"]
-  if stats["step"] < 0:
-    pytest.skip("runtime does not expose jit cache sizes")
   # warm set == cold set == the two designed buckets
   assert sorted(stats["prefill_buckets"]) == [(1, 4), (1, 8)]
 
@@ -179,8 +173,6 @@ def test_spec_window_check_green():
   assert findings == [], findings
   (info,) = infos
   stats = info["compile_stats"]
-  if stats["window"] < 0:
-    pytest.skip("runtime does not expose jit cache sizes")
   # one verify program across greedy + sampled cycles AND a rank walk
   assert stats["window"] == 1
   assert info["rank_walks"] >= 1

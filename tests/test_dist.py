@@ -80,7 +80,6 @@ def test_compressed_psum_shard_map():
   run_in_subprocess("""
       from functools import partial
       from jax.sharding import PartitionSpec as P
-      from jax.experimental.shard_map import shard_map
       from repro.dist.mesh import make_mesh
       from repro.optim.compress import compressed_psum
 
@@ -88,7 +87,7 @@ def test_compressed_psum_shard_map():
       x = jnp.arange(8 * 16, dtype=jnp.float32).reshape(8, 16) / 7.0
       err0 = jnp.zeros((8, 16), jnp.float32)
 
-      @partial(shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
+      @partial(jax.shard_map, mesh=mesh, in_specs=(P("pod"), P("pod")),
                out_specs=(P("pod"), P("pod")))
       def f(xs, es):
           m, e = compressed_psum(xs[0], "pod", es[0])
