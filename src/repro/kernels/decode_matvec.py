@@ -48,6 +48,7 @@ def decode_matvec(x: jax.Array, w: jax.Array, *, block_m: int = 1024,
   return pl.pallas_call(
       functools.partial(_kernel, nm=nm),
       grid=(nn, nm),
+      name="decode_matvec",
       in_specs=[
           pl.BlockSpec((b, bm), lambda i, j: (0, j)),
           pl.BlockSpec((bm, bn), lambda i, j: (j, i)),

@@ -80,6 +80,7 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
       functools.partial(_kernel, nk=nk, bq=bq, bk=bk, scale=scale,
                         causal=causal),
       grid=(b * h, nq, nk),
+      name="flash_attention",
       in_specs=[
           pl.BlockSpec((1, bq, d), lambda g, i, j: (g, i, 0)),
           pl.BlockSpec((1, bk, d), lambda g, i, j: (g, j, 0)),

@@ -48,6 +48,7 @@ def gru_cell(xw: jax.Array, h: jax.Array, u: jax.Array, bias: jax.Array, *,
   return pl.pallas_call(
       _kernel,
       grid=(nh,),
+      name="gru_cell",
       in_specs=[
           pl.BlockSpec((b, 3, bh), lambda i: (0, 0, i)),
           pl.BlockSpec((b, hidden), lambda i: (0, 0)),

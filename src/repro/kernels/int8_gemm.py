@@ -54,6 +54,7 @@ def int8_gemm(x_q: jax.Array, w_q: jax.Array, x_scale: jax.Array,
   return pl.pallas_call(
       functools.partial(_kernel, nm=nm),
       grid=(nn, nm),
+      name="int8_gemm",
       in_specs=[
           pl.BlockSpec((b, bm), lambda i, j: (0, j)),
           pl.BlockSpec((bm, bn), lambda i, j: (j, i)),

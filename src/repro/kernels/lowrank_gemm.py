@@ -56,6 +56,7 @@ def lowrank_gemm(x: jax.Array, u: jax.Array, v: jax.Array, *,
   return pl.pallas_call(
       functools.partial(_kernel, nm=nm),
       grid=(nm + nn,),
+      name="lowrank_gemm",
       in_specs=[
           pl.BlockSpec((b, bm), lambda i: (0, jnp.minimum(i, nm - 1))),
           pl.BlockSpec((bm, r), lambda i: (jnp.minimum(i, nm - 1), 0)),

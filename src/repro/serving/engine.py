@@ -72,7 +72,10 @@ synchronous DS2 inference. The conv frontend streams over mel chunks
 *with receptive-field context carried across chunk boundaries*, so the
 streamed CTC labels match the full-utterance forward exactly; each GRU
 step is the low-batch recurrent GEMM that kernels/decode_matvec and
-kernels/gru_cell target.
+kernels/gru_cell target. Under a profiler session it records spans
+(`repro.runtime.tracing`): `speech.process_chunk` and `speech.run` at
+the top, and inside them `speech.frontend` (the conv feed of every slot)
+and `speech.frame_step` (one masked step, counting its `live` slots).
 """
 from __future__ import annotations
 
@@ -90,6 +93,7 @@ from repro.kernels.dispatch import resolve_policy
 from repro.layers.common import ModelConfig
 from repro.models import deepspeech
 from repro.models.api import cast_kv_cache, get_model
+from repro.runtime import tracing
 from repro.serving.prefix_cache import PrefixCache
 from repro.serving.speculative import (RankController,
                                        accept_longest_prefix,
@@ -1199,14 +1203,15 @@ class StreamingSpeechServer:
               if s is not None and s.pending]
       if not live:
         return emitted
-      x = np.zeros((self.batch, self._gru_in), dtype)
-      mask = np.zeros((self.batch,), bool)
-      for i in live:
-        x[i] = self._slots[i].pending.popleft()
-        mask[i] = True
-      log_probs, self.state = self._frame_step(
-          self.params, self.state, jnp.asarray(x), jnp.asarray(mask))
-      rows = np.asarray(log_probs)
+      with tracing.span("speech.frame_step", live=len(live)):
+        x = np.zeros((self.batch, self._gru_in), dtype)
+        mask = np.zeros((self.batch,), bool)
+        for i in live:
+          x[i] = self._slots[i].pending.popleft()
+          mask[i] = True
+        log_probs, self.state = self._frame_step(
+            self.params, self.state, jnp.asarray(x), jnp.asarray(mask))
+        rows = np.asarray(log_probs)
       best = rows.argmax(axis=-1)
       for i in live:
         slot, b = self._slots[i], int(best[i])
@@ -1258,23 +1263,25 @@ class StreamingSpeechServer:
     if self._mode == "lockstep":
       raise RuntimeError("server is mid-lockstep-utterance; reset() first")
     results = []
-    while self._queue or any(s is not None for s in self._slots):
-      self._admit()
-      for slot in self._slots:
-        if slot is None or slot.flushed:
-          continue
-        end = min(slot.fed + chunk_frames, slot.feats.shape[0])
-        chunk = slot.feats[None, slot.fed:end]
-        slot.fed = end
-        self._feed_slot(slot, chunk, final=end == slot.feats.shape[0])
-      self._decode_pending()
-      for i, slot in enumerate(self._slots):
-        if slot is not None and slot.done:
-          results.append(SpeechResult(
-              uid=slot.uid, labels=slot.labels,
-              frames=int(slot.feats.shape[0]),
-              log_probs=np.stack(slot.log_probs)))
-          self._slots[i] = None
+    with tracing.span("speech.run"):
+      while self._queue or any(s is not None for s in self._slots):
+        self._admit()
+        with tracing.span("speech.frontend"):
+          for slot in self._slots:
+            if slot is None or slot.flushed:
+              continue
+            end = min(slot.fed + chunk_frames, slot.feats.shape[0])
+            chunk = slot.feats[None, slot.fed:end]
+            slot.fed = end
+            self._feed_slot(slot, chunk, final=end == slot.feats.shape[0])
+        self._decode_pending()
+        for i, slot in enumerate(self._slots):
+          if slot is not None and slot.done:
+            results.append(SpeechResult(
+                uid=slot.uid, labels=slot.labels,
+                frames=int(slot.feats.shape[0]),
+                log_probs=np.stack(slot.log_probs)))
+            self._slots[i] = None
     self._mode = None
     return results
 
@@ -1327,17 +1334,19 @@ class StreamingSpeechServer:
     chunk; a redundant final/flush is a no-op, new frames after it
     require reset()."""
     feats = np.asarray(feats)
-    if self._finished:
-      if feats.shape[1]:
-        raise RuntimeError("utterance already finalized; reset() first")
-      return [[] for _ in range(self.batch)]
-    slots = self._lockstep_slots()
-    for i, slot in enumerate(slots):
-      self._feed_slot(slot, feats[i:i + 1] if feats.shape[1] else None,
-                      final=final)
-    if final:
-      self._finished = True
-    return self._decode_pending()
+    with tracing.span("speech.process_chunk"):
+      if self._finished:
+        if feats.shape[1]:
+          raise RuntimeError("utterance already finalized; reset() first")
+        return [[] for _ in range(self.batch)]
+      slots = self._lockstep_slots()
+      with tracing.span("speech.frontend"):
+        for i, slot in enumerate(slots):
+          self._feed_slot(slot, feats[i:i + 1] if feats.shape[1] else None,
+                          final=final)
+      if final:
+        self._finished = True
+      return self._decode_pending()
 
   def flush(self) -> list:
     """Drain the right-edge conv context at end of utterance. The right

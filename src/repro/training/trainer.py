@@ -6,6 +6,10 @@ The step function is a single jit containing fwd+bwd (scanned over
 microbatches), the regularizer, and the optimizer update — the same
 program the dry-run lowers for the production mesh. Stage transitions
 re-jit (params change structure: full-rank factored -> truncated).
+Under a profiler session each `Trainer.train_step` records a
+`train.step` span (`repro.runtime.tracing`) holding `train.dispatch`
+(the jitted call) and `train.fetch` (the metrics' transfer), whose child
+`train.wait` is the wait for the device to finish the step.
 """
 from __future__ import annotations
 
@@ -26,6 +30,7 @@ from repro.dist.sharding import (Constraint, identity_constraint,
 from repro.layers.common import ModelConfig
 from repro.models.api import ModelApi, get_model
 from repro.optim import AdamWConfig, make_optimizer
+from repro.runtime import tracing
 
 
 @dataclasses.dataclass(frozen=True)
@@ -171,19 +176,29 @@ class Trainer:
   # -- stepping ---------------------------------------------------------------
 
   def train_step(self, batch: dict) -> dict:
-    self.maybe_transition()
-    t0 = time.perf_counter()
-    self.params, self.opt_state, metrics = self._step_fn(
-        self.params, self.opt_state, batch, jnp.asarray(self.step))
-    metrics = {k: float(v) for k, v in metrics.items()}
-    metrics["step"] = self.step
-    metrics["stage"] = self.stage
-    metrics["wall_s"] = time.perf_counter() - t0
-    self.metrics_history.append(metrics)
-    self.step += 1
-    if (self.ckpt and self.train_cfg.checkpoint_every and
-        self.step % self.train_cfg.checkpoint_every == 0):
-      self.save()
+    """One step; `wall_s` runs from the dispatch (the batch's copy to
+    the device included) to the end of the metrics fetch."""
+    with tracing.span("train.step"):
+      self.maybe_transition()
+      t0 = time.perf_counter_ns()
+      with tracing.span("train.dispatch") as sp:
+        sp.stamp(start_ns=t0)
+        self.params, self.opt_state, metrics = self._step_fn(
+            self.params, self.opt_state, batch, jnp.asarray(self.step))
+      with tracing.span("train.fetch") as sp:
+        with tracing.span("train.wait"):
+          jax.block_until_ready(metrics)
+        metrics = {k: float(v) for k, v in metrics.items()}
+        t1 = time.perf_counter_ns()
+        sp.stamp(end_ns=t1)
+      metrics["step"] = self.step
+      metrics["stage"] = self.stage
+      metrics["wall_s"] = (t1 - t0) * 1e-9
+      self.metrics_history.append(metrics)
+      self.step += 1
+      if (self.ckpt and self.train_cfg.checkpoint_every and
+          self.step % self.train_cfg.checkpoint_every == 0):
+        self.save()
     return metrics
 
   def tracenorm_report(self) -> dict:
