@@ -942,22 +942,26 @@ def _same_pad(size: int, kernel: int, stride: int) -> tuple[int, int]:
 
 
 class _ConvStream:
-  """One strided-conv stage streamed over time.
+  """Host bookkeeping of one strided-conv stage streamed over time.
 
   Implements the `deepspeech.conv_time_pads` convention: a fixed left
   pad of (k - s) // 2 zeros is materialized once at stream start, pushed
-  frames are buffered, and output frame j is emitted as soon as its
+  frames are buffered, and output frame j is ready as soon as its
   receptive field [j*s - pl, j*s - pl + k) is complete. `flush` computes
   the right pad *from the actual frame count* — exactly the zeros needed
   to complete ceil(n_in / s) output frames — so chunked emission equals
   the full-utterance conv frame-for-frame for ANY utterance length, not
-  just stride multiples (the old fixed right pad asserted alignment).
+  just stride multiples.
+
+  The stream runs no conv itself: `push` and `flush` hand back the input
+  window of the output frames that just became complete, (m - 1) * s + k
+  frames for m outputs, and the server convolves every stream's window
+  of a stage in one gathered call.
   """
 
-  def __init__(self, kernel: int, stride: int, apply_fn):
+  def __init__(self, kernel: int, stride: int):
     self.k, self.s = kernel, stride
     self.pad_l = (kernel - stride) // 2
-    self.apply = apply_fn        # (b, t, ...) -> outputs, VALID in time
     self.buf: Optional[np.ndarray] = None
     self.n_in = 0                # frames received, padding excluded
     self.n_out = 0               # frames emitted so far
@@ -965,6 +969,17 @@ class _ConvStream:
 
   def _zeros(self, like: np.ndarray, t: int) -> np.ndarray:
     return np.zeros((like.shape[0], t) + like.shape[2:], like.dtype)
+
+  def _append(self, x) -> None:
+    x = np.asarray(x)
+    if x.shape[1] == 0:
+      return
+    if self.buf is None:
+      self.buf = np.concatenate([self._zeros(x, self.pad_l), x], axis=1)
+    else:
+      self.buf = np.concatenate([self.buf, x.astype(self.buf.dtype)],
+                                axis=1)
+    self.n_in += x.shape[1]
 
   def _emit(self) -> Optional[np.ndarray]:
     n = self.buf.shape[1]
@@ -974,29 +989,28 @@ class _ConvStream:
     window = self.buf[:, :(m - 1) * self.s + self.k]
     self.buf = self.buf[:, m * self.s:]
     self.n_out += m
-    return np.asarray(self.apply(window))
+    return window
 
   def push(self, x) -> Optional[np.ndarray]:
+    """Buffer frames x (1, t, ...); the window of the outputs now
+    complete, or None."""
     if self.flushed:
       raise RuntimeError("conv stream already flushed; reset() first")
-    x = np.asarray(x)
-    if x.shape[1] == 0:
-      return None
-    if self.buf is None:
-      self.buf = np.concatenate([self._zeros(x, self.pad_l), x], axis=1)
-    else:
-      self.buf = np.concatenate([self.buf, x.astype(self.buf.dtype)],
-                                axis=1)
-    self.n_in += x.shape[1]
-    return self._emit()
+    self._append(x)
+    return None if self.buf is None else self._emit()
 
-  def flush(self) -> Optional[np.ndarray]:
+  def flush(self, tail=None) -> Optional[np.ndarray]:
+    """End of stream: buffer the last frames `tail`, if any, right-pad,
+    and return the window of every output not yet emitted, or None."""
     # idempotent: re-flushing must not re-pad the residual buffer and
     # complete a fake window
-    if self.buf is None or self.flushed:
-      self.flushed = True
+    if self.flushed:
       return None
     self.flushed = True
+    if tail is not None:
+      self._append(tail)
+    if self.buf is None:
+      return None
     out_total = -(-self.n_in // self.s)
     pad_r = (out_total - 1) * self.s + self.k - self.pad_l - self.n_in
     if pad_r > 0:
@@ -1062,6 +1076,9 @@ class StreamingSpeechServer:
     across retire -> refill, exactly like `LMEngine`'s decode step.
     Slot admission zeroes the slot's GRU rows through the jitted
     `ModelApi.insert_slot` surgery (traced slot index: one program).
+    The frontend is gathered the same way: each conv stage runs as one
+    `batch_size`-row call per iteration over every slot's ready window,
+    flush windows included, never once per slot.
 
   * **Lockstep** (`process_chunk` / `flush`): the legacy single-group
     API — all `batch_size` streams advance through the same chunk
@@ -1144,49 +1161,60 @@ class StreamingSpeechServer:
 
   # -- shared machinery -----------------------------------------------------
 
-  def _bucketed(self, fn, kernel, stride, buckets: set, window):
-    """Run a VALID-in-time conv over `window`, padded on the right to a
-    pow2 time bucket so a stream's varying window lengths reuse a small
-    set of jit signatures; the pad only creates extra output frames past
-    the real ones, which are sliced off (VALID conv is local)."""
-    t = window.shape[1]
-    m = (t - kernel) // stride + 1
-    tp = max(_next_pow2(t), kernel)
-    if tp != t:
-      pad = np.zeros((window.shape[0], tp - t) + window.shape[2:],
-                     window.dtype)
-      window = np.concatenate([window, pad], axis=1)
+  def _gathered(self, stage: int, windows: dict) -> dict:
+    """Run conv stage `stage` (0 or 1), VALID in time, over every slot's
+    ready window {slot index: (1, t, ...) or None} in ONE call. Row i
+    holds slot i's window, zero-padded on the right to the pow2 time
+    bucket of the longest window, or of 2 * kernel if that is longer;
+    rows without a window are zeros and their outputs are dropped. The
+    batch stays `batch_size`, so a stage keeps one jit signature per
+    bucket; the pad only creates output frames past each row's real
+    ones, which are sliced off (VALID conv is local in time and rows
+    never mix). The floor: which slots' windows meet in a call, and so
+    the longest, depends on the slot mix, and short windows (every flush
+    window, and all of them at chunks up to about `kernel` frames) must
+    not reach a bucket that a warm-up with another mix never compiled.
+    Returns {slot index: (1, m, ...)}."""
+    fn, buckets = ((self._conv1, self._buckets1),
+                   (self._conv2, self._buckets2))[stage]
+    kernel, stride = self._geom[2 * stage:2 * stage + 2]
+    windows = {i: w for i, w in windows.items() if w is not None}
+    if not windows:
+      return {}
+    tp = _next_pow2(max(max(w.shape[1] for w in windows.values()),
+                        2 * kernel))
+    like = next(iter(windows.values()))
+    x = np.zeros((self.batch, tp) + like.shape[2:], like.dtype)
+    for i, w in windows.items():
+      x[i, :w.shape[1]] = w[0]
     buckets.add(tp)
-    return np.asarray(fn(self.params, jnp.asarray(window)))[:, :m]
+    y = np.asarray(fn(self.params, jnp.asarray(x)))
+    return {i: y[i:i + 1, :(w.shape[1] - kernel) // stride + 1]
+            for i, w in windows.items()}
 
   def _make_streams(self):
-    s1 = _ConvStream(self._geom[0], self._geom[1],
-                     lambda x: self._bucketed(self._conv1, self._geom[0],
-                                              self._geom[1],
-                                              self._buckets1, x))
-    s2 = _ConvStream(self._geom[2], self._geom[3],
-                     lambda x: self._bucketed(self._conv2, self._geom[2],
-                                              self._geom[3],
-                                              self._buckets2, x))
-    return s1, s2
+    return (_ConvStream(self._geom[0], self._geom[1]),
+            _ConvStream(self._geom[2], self._geom[3]))
 
-  def _feed_slot(self, slot: _SpeechSlot, feats, *, final: bool) -> None:
-    """Push raw mel frames (1, t, f) through the slot's conv streams;
-    queue every completed post-frontend frame for decoding."""
-    outs = []
-    if feats is not None and feats.shape[1]:
-      y1 = slot.s1.push(feats)
-      if y1 is not None and y1.shape[1]:
-        outs.append(slot.s2.push(y1))
-    if final and not slot.flushed:
-      y1 = slot.s1.flush()
-      if y1 is not None and y1.shape[1]:
-        outs.append(slot.s2.push(y1))
-      outs.append(slot.s2.flush())
-      slot.flushed = True
-    for o in outs:
-      if o is not None and o.shape[1]:
-        slot.pending.extend(np.asarray(o[0]))
+  def _feed(self, chunks: dict, finals) -> None:
+    """Push raw mel chunks {slot index: (1, t, f)} through their slots'
+    conv streams, flushing the streams of the slots in `finals` (their
+    last chunk, if any, goes into the flush): ONE gathered conv call per
+    stage, whatever the number of slots and of streams that end.
+    Receptive-field context stays in each slot's own streams. Queues
+    every completed post-frontend frame for decoding."""
+    slots, finals = self._slots, set(finals)
+
+    def ready(i, stream, x):
+      return stream.flush(x) if i in finals else stream.push(x)
+    y1 = self._gathered(0, {i: ready(i, slots[i].s1, chunks.get(i))
+                            for i in finals | set(chunks)})
+    out = self._gathered(1, {i: ready(i, slots[i].s2, y1.get(i))
+                             for i in finals | set(y1)})
+    for i in finals:
+      slots[i].flushed = True
+    for i, o in out.items():
+      slots[i].pending.extend(o[0])
 
   def _decode_pending(self) -> list:
     """Masked frame steps until no live slot has a pending frame.
@@ -1267,13 +1295,16 @@ class StreamingSpeechServer:
       while self._queue or any(s is not None for s in self._slots):
         self._admit()
         with tracing.span("speech.frontend"):
-          for slot in self._slots:
+          chunks, finals = {}, []
+          for i, slot in enumerate(self._slots):
             if slot is None or slot.flushed:
               continue
             end = min(slot.fed + chunk_frames, slot.feats.shape[0])
-            chunk = slot.feats[None, slot.fed:end]
+            chunks[i] = slot.feats[None, slot.fed:end]
             slot.fed = end
-            self._feed_slot(slot, chunk, final=end == slot.feats.shape[0])
+            if end == slot.feats.shape[0]:
+              finals.append(i)
+          self._feed(chunks, finals)
         self._decode_pending()
         for i, slot in enumerate(self._slots):
           if slot is not None and slot.done:
@@ -1314,7 +1345,7 @@ class StreamingSpeechServer:
     self._mode = None
     self._finished = False
 
-  def _lockstep_slots(self) -> list:
+  def _lockstep_slots(self) -> None:
     if self._mode == "fleet":
       raise RuntimeError("server is mid-fleet-run; reset() first")
     self._mode = "lockstep"
@@ -1322,7 +1353,6 @@ class StreamingSpeechServer:
       for i in range(self.batch):
         s1, s2 = self._make_streams()
         self._slots[i] = _SpeechSlot(None, None, s1, s2)
-    return self._slots
 
   def process_chunk(self, feats: np.ndarray, *,
                     final: bool = False) -> list:
@@ -1339,11 +1369,11 @@ class StreamingSpeechServer:
         if feats.shape[1]:
           raise RuntimeError("utterance already finalized; reset() first")
         return [[] for _ in range(self.batch)]
-      slots = self._lockstep_slots()
+      self._lockstep_slots()
       with tracing.span("speech.frontend"):
-        for i, slot in enumerate(slots):
-          self._feed_slot(slot, feats[i:i + 1] if feats.shape[1] else None,
-                          final=final)
+        self._feed({i: feats[i:i + 1] for i in range(self.batch)}
+                   if feats.shape[1] else {},
+                   range(self.batch) if final else ())
       if final:
         self._finished = True
       return self._decode_pending()
